@@ -37,8 +37,9 @@ chaos-smoke:
 
 # tracked perf baseline (non-tier-1): vectorized cache lookup rows/s vs the
 # retained reference loop (>=3x floor at batch 256 / zipf 1.1, bit-identical
-# outputs + counters), pipelined vs sequential GRASP dist step (bit-exact
-# loss+params on the 8-device mesh), and the hot_gather kernel microbench;
+# outputs + counters), pipelined vs sequential GRASP dist step (loss and
+# params within 1e-6 on the 8-device mesh), and the hot_gather kernel
+# microbench (all timed on the host CPU);
 # emits BENCH_perf.json
 perf-smoke:
 	PYTHONPATH=src python -m benchmarks.perf_smoke --out BENCH_perf.json
@@ -46,4 +47,4 @@ perf-smoke:
 # launch the gateway for manual poking (recsys engine on :8077):
 #   curl -s -XPOST localhost:8077/v1/score -d '{"hist":[1,2,3],"candidates":[4,5]}'
 gateway:
-	PYTHONPATH=src python -m repro.launch.serve --engine recsys --gateway 127.0.0.1:8077
+	PYTHONPATH=src python -m repro.launch.serve --engine recsys --smoke --gateway 127.0.0.1:8077

@@ -12,10 +12,10 @@ faster wrong answer can never pass:
               zipf a=1.1 stream.
   dist        ``make_grasp_gin_step`` pipelined (overlap=True, the
               default) vs sequential (overlap=False) on the simulated
-              8-device mesh: asserts bit-identical loss AND params over
-              multiple steps, reports per-step wall time and collective
-              counts (the pipelined exchange issues L fused all_gathers
-              per step instead of 2L).
+              8-device mesh: asserts loss and params agree to float32
+              rounding (1e-6) over multiple steps, reports per-step wall
+              time and collective counts (the pipelined exchange issues L
+              fused all_gathers per step instead of 2L).
   hot_gather  the Pallas hot-region gather kernel microbench
               (interpret mode on CPU), checked against the dense
               reference gather.
@@ -26,6 +26,11 @@ are refreshed from, and the trajectory regressions are caught against.
     PYTHONPATH=src python -m benchmarks.perf_smoke [--out BENCH_perf.json]
 
 Non-tier-1: wired into scripts/verify.sh after the tier-1 steps.
+
+A CPU-only tool: it forces 8 host CPU devices for the dist section, so it
+never runs on a TPU, and every time it reports is a host-CPU time, not a
+device measurement. ``chip_smoke.py`` at the repository root is what runs
+on the chip.
 """
 from __future__ import annotations
 
@@ -131,7 +136,8 @@ def bench_lookup():
 
 
 def bench_dist(steps: int = 5):
-    """Pipelined vs sequential GRASP exchange: bit-exact, then timed."""
+    """Pipelined vs sequential GRASP exchange: equal to f32 rounding,
+    then timed."""
     import jax
     import jax.numpy as jnp
 
@@ -206,14 +212,16 @@ def bench_dist(steps: int = 5):
         print(f"[perf-smoke] dist {name:10s}: "
               f"{out[name]['step_ms']:7.1f} ms/step  loss[0]={losses[0]:.6f}")
 
-    assert traj["sequential"] == traj["pipelined"], (
-        "pipelined GRASP step loss diverged from sequential: "
-        f"{traj['sequential']} != {traj['pipelined']}")
+    np.testing.assert_allclose(
+        traj["pipelined"], traj["sequential"], rtol=1e-6, atol=0,
+        err_msg="pipelined GRASP step loss diverged from sequential")
     leaves_s = jax.tree_util.tree_leaves(final_params["sequential"])
     leaves_p = jax.tree_util.tree_leaves(final_params["pipelined"])
-    assert all(bool((a == b).all()) for a, b in zip(leaves_s, leaves_p)), \
-        "pipelined GRASP step params diverged from sequential"
-    out["bit_exact"] = True
+    for a, b in zip(leaves_s, leaves_p):
+        np.testing.assert_allclose(
+            np.asarray(b), np.asarray(a), rtol=0, atol=1e-6,
+            err_msg="pipelined GRASP step params diverged from sequential")
+    out["matches_within_1e-6"] = True
     out["speedup"] = (out["sequential"]["step_ms"]
                       / out["pipelined"]["step_ms"])
     return out
@@ -223,26 +231,25 @@ def bench_hot_gather(iters: int = 10):
     """Pinned-hot-region Pallas gather microbench (interpret on CPU)."""
     import jax.numpy as jnp
 
+    from repro import kernels
     from repro.kernels.hot_gather.hot_gather import hot_gather_hot_part
 
-    hot, d, e, tile = 512, 128, 4096, 512
+    hot, d, e, tile = 512, 128, 4096, 1024
     rng = np.random.default_rng(0)
     table = rng.standard_normal((hot, d)).astype(np.float32)
     idx = rng.integers(-1, hot, e).astype(np.int32)   # -1 = cold fixup rows
     table_j, idx_j = jnp.asarray(table), jnp.asarray(idx)
 
-    rows = np.asarray(hot_gather_hot_part(table_j, idx_j, tile_e=tile,
-                                          interpret=True))
+    rows = np.asarray(hot_gather_hot_part(table_j, idx_j, tile_e=tile))
     want = np.where((idx >= 0)[:, None], table[np.clip(idx, 0, hot - 1)], 0.0)
     assert (rows == want).all(), "hot_gather kernel != dense reference gather"
 
     t0 = time.perf_counter()
     for _ in range(iters):
-        hot_gather_hot_part(table_j, idx_j, tile_e=tile,
-                            interpret=True).block_until_ready()
+        hot_gather_hot_part(table_j, idx_j, tile_e=tile).block_until_ready()
     dt = time.perf_counter() - t0
     out = {"hot_rows": hot, "dim": d, "idx_len": e, "tile_e": tile,
-           "interpret": True, "rows_per_s": e * iters / dt}
+           "interpret": kernels.interpret(), "rows_per_s": e * iters / dt}
     print(f"[perf-smoke] hot_gather (interpret): "
           f"{out['rows_per_s']:.0f} rows/s over {iters} iters")
     return out
@@ -266,7 +273,7 @@ def main(argv=None):
         "verdict": {
             "lookup_speedup_at_accept": accept["speedup"],
             "lookup_accept_floor": ACCEPT_SPEEDUP,
-            "dist_bit_exact": dist.get("bit_exact", None),
+            "dist_matches_within_1e-6": dist.get("matches_within_1e-6"),
             "dist_speedup": dist.get("speedup", None),
         },
     }

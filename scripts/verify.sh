@@ -10,8 +10,9 @@ python -m pytest -q -m "not slow"
 XLA_FLAGS=--xla_force_host_platform_device_count=8 \
   PYTHONPATH=src python tests/helpers/grasp_gnn_equivalence.py
 
-# 8-device bit-exactness of the pipelined (overlap=True) GRASP step vs the
-# sequential exchange: identical loss AND params over multiple layers/steps
+# 8-device check of the pipelined (overlap=True) GRASP step vs the
+# sequential exchange: loss and params equal to float32 rounding (1e-6)
+# over multiple layers/steps
 XLA_FLAGS=--xla_force_host_platform_device_count=8 \
   PYTHONPATH=src python tests/helpers/grasp_pipeline_equivalence.py
 
@@ -32,7 +33,7 @@ PYTHONPATH=src timeout 600 python -m benchmarks.chaos_smoke --out BENCH_chaos.js
 
 # non-tier-1: tracked perf baseline (vectorized lookup >=3x the retained
 # reference loop with bit-identical outputs/counters, pipelined dist step
-# bit-exact vs sequential, hot_gather microbench); emits BENCH_perf.json
+# within 1e-6 of sequential, hot_gather microbench); emits BENCH_perf.json
 PYTHONPATH=src timeout 600 python -m benchmarks.perf_smoke --out BENCH_perf.json
 
 echo "verify: OK"

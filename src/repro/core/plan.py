@@ -27,10 +27,11 @@ import numpy as np
 
 from repro.core.regions import GraspRegions, make_regions
 
-# v5e-class geometry. VMEM is the fast-memory tier for the kernel plan; a
-# fraction is reserved for streaming buffers / activations.
-VMEM_BYTES = 128 * 1024 * 1024
-DEFAULT_VMEM_FRACTION = 0.5
+# Scoped VMEM one Pallas kernel may use on TPU v5e at the compiler's default
+# limit. Mosaic states it when a kernel asks for more: "Scoped allocation
+# with size 17.00M and limit 16.00M exceeded scoped vmem limit".
+KERNEL_VMEM_BYTES = 16 << 20
+SUBLANES = 8
 
 
 def entries_for_budget(
@@ -53,6 +54,20 @@ def entries_for_budget(
     if align > 1:
         n -= n % align
     return int(n)
+
+
+def kernel_hot_rows(row_bytes: int, tile_rows: int,
+                    max_rows: Optional[int] = None) -> int:
+    """Rows of a VMEM-pinned hot block that fit one kernel's scoped VMEM.
+
+    ``row_bytes`` is the lane-padded row size. The kernel's other VMEM user
+    is its double-buffered ``(tile_rows, row)`` output tile. The result is
+    a multiple of the sublane tile, then clamped to ``max_rows``. Every
+    VMEM-pinned tier asks here: the serving cache and the kernel wrappers.
+    """
+    budget = KERNEL_VMEM_BYTES - 2 * tile_rows * row_bytes
+    n = entries_for_budget(budget, row_bytes, align=SUBLANES)
+    return n if max_rows is None else min(n, int(max_rows))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -96,7 +111,7 @@ class GraspPlan:
 def make_plan(
     num_elems: int,
     elem_bytes: int,
-    budget_bytes: Optional[int] = None,
+    budget_bytes: int,
     num_arrays: int = 1,
     align: int = 1,
 ) -> GraspPlan:
@@ -107,8 +122,6 @@ def make_plan(
     from the *policies* staying flexible, not from disabling the plan
     (paper Sec. V-B).
     """
-    if budget_bytes is None:
-        budget_bytes = int(VMEM_BYTES * DEFAULT_VMEM_FRACTION)
     per_array = budget_bytes // max(num_arrays, 1)
     hot = entries_for_budget(per_array, elem_bytes, align=align,
                              max_entries=num_elems)

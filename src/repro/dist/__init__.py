@@ -4,29 +4,4 @@
 by the launch layer (steps/dryrun/train/serve); ``repro.dist.collectives``
 is the GRASP distributed exchange — hot-prefix replication with a bounded
 cold halo (paper Table I lifted to the partition tier).
-
-Importing this package also installs two tiny jax compatibility aliases so
-the launch code and tests run on the older jax pinned in this container:
-``jax.set_mesh`` (context-manager form) and ``jax.shard_map``. Both are
-no-ops on jax versions that already provide them.
 """
-import contextlib
-
-import jax
-
-if not hasattr(jax, "shard_map"):
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    jax.shard_map = _shard_map
-
-if not hasattr(jax, "set_mesh"):
-
-    @contextlib.contextmanager
-    def _set_mesh(mesh):
-        # jax.sharding.Mesh is itself a context manager (sets the global
-        # resource env); NamedSharding-carrying jit does not strictly need
-        # it, but shard_map/legacy pjit paths do.
-        with mesh:
-            yield mesh
-
-    jax.set_mesh = _set_mesh

@@ -32,14 +32,13 @@ def rmat(
     src = np.zeros(m, dtype=np.int64)
     dst = np.zeros(m, dtype=np.int64)
     ab = a + b
-    a_norm = a / ab
-    c_norm = c / (1.0 - ab)
+    # quadrants (src bit, dst bit): a=(0,0), b=(0,1), c=(1,0), d=(1,1)
+    p_dst_one = np.array([b / ab, (1.0 - ab - c) / (1.0 - ab)])
     for bit in range(scale):
-        go_right_src = rng.random(m) > ab  # choose bottom half for src bit
-        p_dst = np.where(go_right_src, c_norm, a_norm)
-        go_right_dst = rng.random(m) > (1.0 - p_dst)  # bottom half for dst
-        src |= go_right_src.astype(np.int64) << bit
-        dst |= go_right_dst.astype(np.int64) << bit
+        src_bit = rng.random(m) >= ab
+        dst_bit = rng.random(m) < p_dst_one[src_bit.astype(np.int64)]
+        src |= src_bit.astype(np.int64) << bit
+        dst |= dst_bit.astype(np.int64) << bit
     # permute vertex labels so degree is NOT correlated with vertex id —
     # this mirrors real datasets where hot vertices are scattered in the id
     # space (the paper's "lack of spatial locality" problem).

@@ -16,6 +16,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro import kernels
+
 
 def _bag_kernel(ids_ref, mask_ref, hot_ref, out_ref, *, hot_size: int):
     ids = ids_ref[...]                       # (tile_b, H) int32
@@ -30,13 +32,12 @@ def _bag_kernel(ids_ref, mask_ref, hot_ref, out_ref, *, hot_size: int):
     )
 
 
-@functools.partial(jax.jit, static_argnames=("tile_b", "interpret"))
+@functools.partial(jax.jit, static_argnames=("tile_b",))
 def hot_bag_hot_part(
     hot_table: jnp.ndarray,    # (H_rows, d) pinned hot prefix
     ids: jnp.ndarray,          # (B, H) int32
     mask: jnp.ndarray,         # (B, H) bool
     tile_b: int = 256,
-    interpret: bool = True,
 ) -> jnp.ndarray:
     hr, d = hot_table.shape
     b, hlen = ids.shape
@@ -52,5 +53,5 @@ def hot_bag_hot_part(
         ],
         out_specs=pl.BlockSpec((tile_b, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((b, d), jnp.float32),
-        interpret=interpret,
+        interpret=kernels.interpret(),
     )(ids, mask, hot_table)

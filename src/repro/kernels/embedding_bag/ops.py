@@ -7,7 +7,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.core import plan as plan_mod
 from repro.core.plan import GraspPlan
 from repro.kernels.embedding_bag.embedding_bag import hot_bag_hot_part
 from repro.kernels.hot_gather.ops import hot_gather
@@ -16,22 +15,16 @@ LANE = 128
 
 
 def hot_lookup(table: jnp.ndarray, ids: jnp.ndarray,
-               plan: Optional[GraspPlan] = None, interpret: bool = True):
-    """(V,d) x (B,) -> (B,d); hot prefix from VMEM, cold fixup bounded."""
-    if plan is not None:
-        hot_size = plan.hot_size
-    else:
-        # default: the VMEM-budget share of the table (== 2^18 rows at d=64)
-        hot_size = plan_mod.entries_for_budget(
-            int(plan_mod.VMEM_BYTES * plan_mod.DEFAULT_VMEM_FRACTION),
-            table.shape[1] * table.dtype.itemsize,
-            max_entries=table.shape[0],
-        )
-    return hot_gather(table, ids, hot_size=hot_size, interpret=interpret)
+               plan: Optional[GraspPlan] = None):
+    """(V,d) x (B,) -> (B,d); hot prefix from VMEM, cold fixup bounded.
+    Without a plan the hot prefix is the largest block the kernel's VMEM
+    allows."""
+    return hot_gather(table, ids,
+                      hot_size=None if plan is None else plan.hot_size)
 
 
 @functools.partial(jax.jit, static_argnames=("hot_size", "cold_capacity",
-                                             "tile_b", "interpret"))
+                                             "tile_b"))
 def hot_bag(
     table: jnp.ndarray,       # (V, d)
     ids: jnp.ndarray,         # (B, H)
@@ -39,7 +32,6 @@ def hot_bag(
     hot_size: int,
     cold_capacity: Optional[int] = None,
     tile_b: int = 256,
-    interpret: bool = True,
 ) -> jnp.ndarray:
     """Fused EmbeddingBag(sum): kernel handles hot rows; cold rows are
     compacted, gathered once from HBM and segment-summed into the bags."""
@@ -55,8 +47,7 @@ def hot_bag(
     ids_p = jnp.pad(ids, ((0, b_pad - b), (0, 0)), constant_values=-1)
     mask_p = jnp.pad(mask, ((0, b_pad - b), (0, 0)), constant_values=False)
 
-    out = hot_bag_hot_part(hot, ids_p, mask_p, tile_b=tile_b,
-                           interpret=interpret)[:b, :d]
+    out = hot_bag_hot_part(hot, ids_p, mask_p, tile_b=tile_b)[:b, :d]
 
     # cold fixup: compact cold (id, bag) pairs, gather, segment-sum per bag
     flat_ids = ids.reshape(-1)

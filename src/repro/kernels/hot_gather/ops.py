@@ -16,8 +16,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.plan import GraspPlan
+from repro.core import plan as plan_mod
 from repro.kernels.hot_gather.hot_gather import (
+    IDX_TILE,
     hot_gather_hot_part,
     hot_gather_segment_sum,
 )
@@ -30,30 +31,32 @@ def _pad_rows(e: int, tile: int) -> int:
 
 
 @functools.partial(jax.jit, static_argnames=("hot_size", "cold_capacity",
-                                             "tile_e", "interpret"))
+                                             "tile_e"))
 def hot_gather(
     prop: jnp.ndarray,         # (N, d)
     idx: jnp.ndarray,          # (E,) int32
     hot_size: Optional[int] = None,
     cold_capacity: Optional[int] = None,
-    tile_e: int = 2048,
-    interpret: bool = True,
+    tile_e: int = 2 * IDX_TILE,
 ) -> jnp.ndarray:
-    """Drop-in replacement for ``jnp.take(prop, idx, axis=0)``."""
+    """Drop-in replacement for ``jnp.take(prop, idx, axis=0)``.
+
+    ``hot_size=None`` pins the largest block the kernel's VMEM allows."""
     n, d = prop.shape
     e = idx.shape[0]
+    d_pad = (d + LANE - 1) // LANE * LANE
     if hot_size is None:
-        hot_size = min(n, 1 << 20)
+        hot_size = plan_mod.kernel_hot_rows(d_pad * prop.dtype.itemsize,
+                                            tile_e)
     hot_size = min(hot_size, n)
     if cold_capacity is None:
         cold_capacity = e  # exact by default; plans shrink it via skew
 
-    d_pad = (d + LANE - 1) // LANE * LANE
     e_pad = _pad_rows(e, tile_e)
     hot = jnp.pad(prop[:hot_size], ((0, 0), (0, d_pad - d)))
     idx_p = jnp.pad(idx, (0, e_pad - e), constant_values=-1)
 
-    out = hot_gather_hot_part(hot, idx_p, tile_e=tile_e, interpret=interpret)
+    out = hot_gather_hot_part(hot, idx_p, tile_e=tile_e)
     out = out[:e, :d]
 
     # --- bounded cold fixup (HBM gather of the compacted cold indices) ---
@@ -106,7 +109,6 @@ def hot_gather_segsum_aligned(
     num_segments: int,
     seg_per_tile: int,
     tile_e: int = 2048,
-    interpret: bool = True,
 ) -> jnp.ndarray:
     """Fused hot gather + segment-sum over a pre-aligned edge layout.
 
@@ -120,6 +122,6 @@ def hot_gather_segsum_aligned(
     hot = jnp.pad(hot_table, ((0, 0), (0, d_pad - hot_table.shape[1])))
     out = hot_gather_segment_sum(
         hot, idx_tiles, seg_tiles, num_segments,
-        tile_e=tile_e, seg_per_tile=seg_per_tile, interpret=interpret,
+        tile_e=tile_e, seg_per_tile=seg_per_tile,
     )
     return out[:, : hot_table.shape[1]]

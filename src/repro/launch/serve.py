@@ -1,21 +1,26 @@
 """Serving CLI — a thin front-end over ``repro.serve.engine``.
 
     # transformer prefill+decode loop (the original driver, partial
-    # batches fixed):
-    PYTHONPATH=src python -m repro.launch.serve --engine lm \
+    # batches fixed), reduced config:
+    PYTHONPATH=src python -m repro.launch.serve --engine lm --smoke \
         --arch starcoder2-7b --requests 16 --prefill 64 --decode 32
 
     # MIND candidate scoring through the GRASP embedding cache on a
-    # zipf-skewed stream with deadlines + shed load:
+    # zipf-skewed stream with deadlines + shed load (published widths
+    # without --smoke):
     PYTHONPATH=src python -m repro.launch.serve --engine recsys \
-        --requests 256 --qps 2000 --budget-kb 256 --json /tmp/serve.json
+        --requests 256 --qps 2000 --budget-kb 256 --json serve.json
 
     # put either engine behind the repro.gateway RPC front-end (serves
-    # until Ctrl-C, then drains gracefully):
-    PYTHONPATH=src python -m repro.launch.serve --engine recsys \
+    # until Ctrl-C, then drains gracefully), reduced config:
+    PYTHONPATH=src python -m repro.launch.serve --engine recsys --smoke \
         --gateway 127.0.0.1:8077
     curl -s -XPOST localhost:8077/v1/score \
         -d '{"hist": [1,2,3], "candidates": [4,5]}'
+
+``--smoke`` swaps in the reduced config; without it the published config
+is served. The persistent compile cache follows
+``repro.launch.compile_cache``.
 
 All real logic lives in ``repro.serve``/``repro.gateway``; this module
 only parses flags and prints/emits the metrics snapshot.
@@ -24,6 +29,8 @@ from __future__ import annotations
 
 import argparse
 import json
+
+from repro.launch import compile_cache
 
 
 def _run_gateway(args):
@@ -100,7 +107,9 @@ def main(argv=None):
     ap.add_argument("--no-supervise", action="store_true",
                     help="gateway mode: disable the pump supervisor "
                          "(dead pump threads then stay dead)")
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action="store_true",
+                    help="serve the reduced config instead of the "
+                         "published one")
     ap.add_argument("--requests", type=int, default=16)
     ap.add_argument("--batch", type=int, default=8)
     # lm flags
@@ -122,6 +131,7 @@ def main(argv=None):
     ap.add_argument("--zipf-a", type=float, default=1.1)
     ap.add_argument("--json", default=None, help="write metrics snapshot here")
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     if args.gateway:
         return _run_gateway(args)

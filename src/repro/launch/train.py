@@ -5,7 +5,8 @@
 
 ``--smoke`` swaps in the reduced config (CPU-sized); without it the full
 config is used (requires the production mesh / real accelerators — on this
-container use dryrun.py for full-size validation).
+container use dryrun.py for full-size validation). The persistent compile
+cache follows ``repro.launch.compile_cache``.
 """
 from __future__ import annotations
 
@@ -17,6 +18,7 @@ import numpy as np
 
 from repro.configs import base as cfgs
 from repro.data import pipeline
+from repro.launch import compile_cache
 from repro.nn import transformer as tfm
 from repro.train import ft as ft_mod
 from repro.train import optimizer as opt_mod
@@ -35,6 +37,7 @@ def main(argv=None):
     ap.add_argument("--fail-at", type=int, nargs="*", default=[],
                     help="inject failures at these steps (FT demo)")
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     cfg = cfgs.get_arch(args.arch)
     if cfg.family != "lm":

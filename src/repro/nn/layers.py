@@ -22,8 +22,14 @@ def dense_init(key, d_in: int, d_out: int, scale: Optional[float] = None):
 
 
 def dense(params, x, compute_dtype=jnp.bfloat16):
+    """``x @ w`` in ``compute_dtype``. A float32 ``compute_dtype`` gets
+    float32 products: the TPU's default precision would round float32
+    operands to one bfloat16 pass."""
+    precision = (jax.lax.Precision.HIGHEST
+                 if jnp.dtype(compute_dtype) == jnp.float32 else None)
     return jnp.einsum(
-        "...i,io->...o", x.astype(compute_dtype), params["w"].astype(compute_dtype)
+        "...i,io->...o", x.astype(compute_dtype), params["w"].astype(compute_dtype),
+        precision=precision,
     )
 
 

@@ -19,7 +19,9 @@ embedding-serving cache has exactly that structure, realised in software:
 
 Sizing comes from a *byte* budget via ``core.plan.entries_for_budget`` —
 the same helper the distributed hot-replica plan uses — split between the
-regions by ``hot_fraction``. ``hot_fraction=0`` disables pinning entirely
+regions by ``hot_fraction``; with the kernel on, the pinned region is
+further capped at what one kernel's VMEM holds (``core.plan.kernel_hot_rows``).
+``hot_fraction=0`` disables pinning entirely
 and yields the unpinned RRPV/LRU baselines the smoke benchmark compares
 against.
 
@@ -54,6 +56,7 @@ import numpy as np
 from repro.core import hotset
 from repro.core import plan as plan_mod
 from repro.core.policies import RRPV_LONG, RRPV_MAX
+from repro.kernels.hot_gather.hot_gather import IDX_TILE, hot_gather_hot_part
 from repro.serve.metrics import ServeMetrics
 
 LANE = 128
@@ -72,8 +75,7 @@ class CacheConfig:
     hot_fraction: float = 0.5  # share of budget pinned; 0 => unpinned baseline
     policy: str = "rrpv"       # cold-region scheme: "rrpv" | "lru"
     use_kernel: bool = True    # Pallas hot_gather for the pinned region
-    tile_e: int = 512          # kernel edge-tile (batch is padded up to it)
-    interpret: bool = True     # CPU container; False on real TPUs
+    tile_e: int = IDX_TILE     # kernel index tile (batch is padded up to it)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -124,6 +126,7 @@ class EmbeddingCache:
         capacity = plan_mod.entries_for_budget(
             config.budget_bytes, self.row_bytes, max_entries=self.num_rows
         )
+        d_pad = (self.dim + LANE - 1) // LANE * LANE
         hot = 0
         if config.hot_fraction > 0:
             hot = plan_mod.entries_for_budget(
@@ -131,6 +134,10 @@ class EmbeddingCache:
                 self.row_bytes,
                 max_entries=capacity,
             )
+            if config.use_kernel:
+                # the pinned block is one VMEM operand of the kernel
+                hot = plan_mod.kernel_hot_rows(
+                    d_pad * table.itemsize, config.tile_e, max_rows=hot)
             if degree is not None:
                 # never pin more rows than are actually hot (paper Sec. II-A)
                 hot = min(hot, int(hotset.hot_mask(np.asarray(degree)).sum()))
@@ -143,8 +150,6 @@ class EmbeddingCache:
         # paper's own point — pin the hot region, keep the rest flexible.
 
         # --- device-resident row data ---------------------------------
-        d_pad = (self.dim + LANE - 1) // LANE * LANE
-        self._d_pad = d_pad
         if self.hot_size > 0:
             self._hot_block = jnp.asarray(
                 np.pad(table[: self.hot_size], ((0, 0), (0, d_pad - self.dim)))
@@ -398,16 +403,12 @@ class EmbeddingCache:
             # the backing table IS the hot block (unpadded): a pure host
             # gather, no device→host copy of the pinned region
             return self.table[ids[hot_mask]]
-        from repro.kernels.hot_gather.hot_gather import hot_gather_hot_part
-
         tile = self.config.tile_e
         e_pad = (len(ids) + tile - 1) // tile * tile
         idx = np.where(hot_mask, ids, -1).astype(np.int32)  # misses -> 0 rows
         idx = np.pad(idx, (0, e_pad - len(ids)), constant_values=-1)
-        rows = hot_gather_hot_part(
-            self._hot_block, jnp.asarray(idx), tile_e=tile,
-            interpret=self.config.interpret,
-        )
+        rows = hot_gather_hot_part(self._hot_block, jnp.asarray(idx),
+                                   tile_e=tile)
         return np.asarray(rows)[: len(ids), : self.dim][hot_mask]
 
     # -- warm-restart snapshots ----------------------------------------

@@ -12,5 +12,3 @@ try:
     import hypothesis  # noqa: F401
 except ModuleNotFoundError:
     sys.path.insert(0, os.path.join(os.path.dirname(__file__), "_shims"))
-
-import repro.dist  # noqa: E402,F401  installs jax.set_mesh/jax.shard_map aliases

@@ -1,5 +1,6 @@
-"""GRASP distributed exchange: partition invariants + bit-exact equivalence
-with the unpartitioned reference (subprocess: needs >1 device)."""
+"""GRASP distributed exchange: partition invariants + equivalence with the
+unpartitioned reference and between the two exchange schedules
+(subprocess: needs >1 device)."""
 import os
 import subprocess
 import sys
@@ -53,11 +54,13 @@ def test_partition_halo_is_bounded_by_skew():
 
 
 def test_pipelined_step_matches_sequential_single_device():
-    """The overlap=True (default) pipelined exchange must be bit-exact vs
-    overlap=False. On one device every all_gather is an identity, but the
-    whole pipelined code path (prologue exchange, fused hot+halo buffer,
-    double-buffered feature tables) still executes — the 8-device run is
-    the slow subprocess test below."""
+    """The overlap=True (default) pipelined exchange must match
+    overlap=False to float32 rounding: loss within rtol 1e-6 and params
+    within 1e-6 (the backward pass of the fused gather reorders a few
+    gradient sums, so the match is not bit for bit). On one device every
+    all_gather is an identity, but the whole pipelined code path (prologue
+    exchange, fused hot+halo buffer, double-buffered feature tables) still
+    executes — the 8-device run is the slow subprocess test below."""
     import jax
     import jax.numpy as jnp
 
@@ -105,10 +108,12 @@ def test_pipelined_step_matches_sequential_single_device():
                 losses.append(float(m["loss"]))
         results[overlap] = (losses, p_)
 
-    assert results[False][0] == results[True][0]
+    np.testing.assert_allclose(results[True][0], results[False][0],
+                               rtol=1e-6, atol=0)
     for a, b in zip(jax.tree_util.tree_leaves(results[False][1]),
                     jax.tree_util.tree_leaves(results[True][1])):
-        assert bool((a == b).all())
+        np.testing.assert_allclose(np.asarray(b), np.asarray(a), rtol=0,
+                                   atol=1e-6)
 
 
 @pytest.mark.slow
@@ -123,9 +128,10 @@ def test_grasp_exchange_matches_reference_subprocess():
 
 
 @pytest.mark.slow
-def test_pipelined_step_bit_exact_subprocess():
-    """Pipelined (overlap=True) == sequential GRASP step: identical loss
-    and params over 3 layers x 5 steps on the 8-device mesh."""
+def test_pipelined_step_matches_sequential_subprocess():
+    """Pipelined (overlap=True) == sequential GRASP step to float32
+    rounding (loss rtol 1e-6, params atol 1e-6) over 3 layers x 5 steps on
+    the 8-device mesh."""
     r = subprocess.run(
         [sys.executable, os.path.join(os.path.dirname(__file__), "helpers", "grasp_pipeline_equivalence.py")],
         env={**os.environ, "PYTHONPATH": SRC},

@@ -22,6 +22,20 @@ def tiny_cfg():
     )
 
 
+@pytest.mark.parametrize("compute_dtype, precision", [
+    (jnp.float32, (jax.lax.Precision.HIGHEST,) * 2),
+    (jnp.bfloat16, None),
+])
+def test_dense_float32_asks_for_float32_products(compute_dtype, precision):
+    """A float32 ``compute_dtype`` must not run as one bfloat16 pass on a
+    TPU; bfloat16 keeps the default."""
+    p = L.dense_init(jax.random.PRNGKey(0), 8, 4)
+    jaxpr = jax.make_jaxpr(lambda x: L.dense(p, x, compute_dtype))(
+        jnp.ones((2, 8)))
+    (dot,) = [e for e in jaxpr.eqns if e.primitive.name == "dot_general"]
+    assert dot.params["precision"] == precision
+
+
 def test_decode_matches_forward(tiny_cfg):
     """Teacher-forcing equivalence: full forward logits at position t ==
     decode-with-cache logits after consuming t tokens. This pins down RoPE

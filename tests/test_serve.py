@@ -511,7 +511,7 @@ def test_launch_serve_cli_recsys(tmp_path):
 
     out = tmp_path / "s.json"
     snap = serve_cli.main([
-        "--engine", "recsys", "--requests", "24", "--batch", "4",
+        "--engine", "recsys", "--smoke", "--requests", "24", "--batch", "4",
         "--qps", "1e9", "--budget-kb", "4", "--deadline-ms", "1e9",
         "--json", str(out),
     ])
