@@ -1,0 +1,8 @@
+"""Share (%) of the traced SSSP window in which no operation ran on
+the device: 1 minus the union of device op intervals over the window."""
+
+
+def read(ctx):
+    if ctx["job"] != "sssp" or ctx["trace"] is None:
+        return None
+    return ctx["trace"].idle_pct
