@@ -9,6 +9,10 @@ the TPU-native formulation of the paper's CSR traversal, and the layer the
 
 Direction switching (Ligra's push/pull heuristic) selects pull when the
 active frontier covers more than ``switch_fraction`` of edges.
+
+The gather, the active-flag gather and the reduction run under the
+``repro.obs`` scopes ``edge_map.gather``, ``edge_map.frontier`` and
+``edge_map.reduce``, so a device trace reads each by name.
 """
 from __future__ import annotations
 
@@ -19,6 +23,7 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.graph.csr import DeviceCSR
 
 Reducer = Callable[[jnp.ndarray, jnp.ndarray, int], jnp.ndarray]
@@ -47,13 +52,22 @@ def gather_src(g: DeviceCSR, prop: jnp.ndarray, gather_impl: str = "jnp") -> jnp
     kernel (``repro.kernels.hot_gather``); 'jnp' is the reference path used
     on CPU and inside the distributed step.
     """
-    if gather_impl == "jnp":
-        return jnp.take(prop, g.indices, axis=0)
-    if gather_impl == "pallas_hot":
-        from repro.kernels.hot_gather import ops as hot_ops
+    with jax.named_scope(obs.GATHER):
+        if gather_impl == "jnp":
+            return jnp.take(prop, g.indices, axis=0)
+        if gather_impl == "pallas_hot":
+            from repro.kernels.hot_gather import ops as hot_ops
 
-        return hot_ops.hot_gather(prop, g.indices)
+            return hot_ops.hot_gather(prop, g.indices)
     raise ValueError(gather_impl)
+
+
+def _mask(active, idx, msgs, identity):
+    """Messages whose edge's vertex ``idx`` is inactive become ``identity``."""
+    with jax.named_scope(obs.FRONTIER):
+        mask = jnp.take(active, idx)
+        shape = (-1,) + (1,) * (msgs.ndim - 1)
+        return jnp.where(mask.reshape(shape), msgs, identity)
 
 
 def edge_map_pull(
@@ -73,13 +87,12 @@ def edge_map_pull(
     """
     msgs = gather_src(g, prop, gather_impl)
     if edge_fn is not None:
-        msgs = edge_fn(msgs, g)
+        with jax.named_scope(obs.GATHER):
+            msgs = edge_fn(msgs, g)
     if active_dst is not None:
-        mask = jnp.take(active_dst, g.dst)
-        shape = (-1,) + (1,) * (msgs.ndim - 1)
-        msgs = jnp.where(mask.reshape(shape), msgs, identity)
-    out = reduce_fn(msgs, g.dst, g.num_nodes)
-    return out
+        msgs = _mask(active_dst, g.dst, msgs, identity)
+    with jax.named_scope(obs.REDUCE):
+        return reduce_fn(msgs, g.dst, g.num_nodes)
 
 
 def edge_map_push(
@@ -90,20 +103,25 @@ def edge_map_push(
     reduce_fn: Reducer = min_reduce,
     identity: float = jnp.inf,
     gather_impl: str = "jnp",
-) -> jnp.ndarray:
-    """Push along out-edges. ``g`` must be the out-edge CSR (``transpose``):
-    its ``indices`` are the pushing sources' targets' sources... i.e. for an
-    out-CSR, ``indices`` = destination of each out-edge and ``dst`` = the
-    pushing source. Messages flow source -> destination."""
-    # In the out-edge CSR, g.dst enumerates sources and g.indices targets.
-    msgs = jnp.take(prop, g.dst, axis=0)
-    if edge_fn is not None:
-        msgs = edge_fn(msgs, g)
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """``(out, slots)``: for each vertex v, ``out[v]`` = reduce(edge_fn(prop[u])
+    for u with an arc u -> v), pushed along out-arcs; ``slots`` the arc slots
+    this call processed (int32), i.e. the relaxations it attempted.
+
+    ``g`` is the out-edge CSR (``transpose`` of the in-edge one): ``g.dst``
+    holds the source u of each out-arc and ``g.indices`` its target v.
+    ``active_src`` masks sources: an inactive source's messages are
+    replaced by ``identity`` before the reduction, so every slot, padding
+    included, is processed whatever the frontier."""
+    with jax.named_scope(obs.GATHER):
+        msgs = jnp.take(prop, g.dst, axis=0)
+        if edge_fn is not None:
+            msgs = edge_fn(msgs, g)
     if active_src is not None:
-        mask = jnp.take(active_src, g.dst)
-        shape = (-1,) + (1,) * (msgs.ndim - 1)
-        msgs = jnp.where(mask.reshape(shape), msgs, identity)
-    return reduce_fn(msgs, g.indices, g.num_nodes)
+        msgs = _mask(active_src, g.dst, msgs, identity)
+    with jax.named_scope(obs.REDUCE):
+        out = reduce_fn(msgs, g.indices, g.num_nodes)
+    return out, jnp.int32(g.dst.shape[0])
 
 
 @dataclasses.dataclass(frozen=True)
@@ -114,6 +132,10 @@ class EngineConfig:
 
 def choose_direction(g: DeviceCSR, active: jnp.ndarray, cfg: EngineConfig) -> jnp.ndarray:
     """True -> pull (dense frontier), False -> push (sparse frontier)."""
-    deg = jnp.diff(g.indptr)
-    frontier_edges = jnp.sum(jnp.where(active, deg, 0))
-    return frontier_edges > cfg.switch_fraction * g.indices.shape[0]
+    return frontier_arcs(g, active) > cfg.switch_fraction * g.indices.shape[0]
+
+
+def frontier_arcs(g: DeviceCSR, active: jnp.ndarray) -> jnp.ndarray:
+    """The arcs of the ``active`` vertices' CSR rows (for the out-edge CSR,
+    their out-arcs), as int32."""
+    return jnp.sum(jnp.where(active, jnp.diff(g.indptr), 0), dtype=jnp.int32)

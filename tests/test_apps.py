@@ -115,7 +115,7 @@ def test_engine_pull_push_consistency(g):
     prop = jnp.asarray(np.random.default_rng(0).random(g.num_nodes),
                        dtype=jnp.float32)
     pull = edge_map_pull(g.device(), prop, reduce_fn=sum_reduce)
-    push = edge_map_push(
+    push, _ = edge_map_push(
         transpose(g).device(), prop, reduce_fn=sum_reduce, identity=0.0
     )
     assert np.allclose(np.asarray(pull), np.asarray(push), atol=1e-3)

@@ -65,20 +65,60 @@ def test_hot_gather_compiles_at_the_vmem_cap(one_chip):
         lower(cap + plan_mod.SUBLANES).compile()
 
 
-def test_pagerank_compiles_at_scale_22(one_chip):
-    from repro.apps.pagerank import pagerank
+def _graph(n, m, weights, sharding):
     from repro.graph.csr import DeviceCSR
+
+    return DeviceCSR(indptr=_sds((n + 1,), jnp.int32, sharding),
+                     indices=_sds((m,), jnp.int32, sharding),
+                     dst=_sds((m,), jnp.int32, sharding),
+                     weights=_sds((m,), jnp.float32, sharding)
+                     if weights else None, num_nodes=n)
+
+
+def test_pagerank_compiles_at_scale_22(one_chip):
+    from repro.apps.pagerank import pagerank_loop
 
     n = 1 << 22
     m = 16 * n  # Graph500 edge factor 16, before duplicates are dropped
-    g = DeviceCSR(indptr=_sds((n + 1,), jnp.int32, one_chip),
-                  indices=_sds((m,), jnp.int32, one_chip),
-                  dst=_sds((m,), jnp.int32, one_chip),
-                  weights=None, num_nodes=n)
-    compiled = pagerank.lower(g, damping=0.85, tol=1e-6 / n,
-                              max_iters=300).compile()
+    g = _graph(n, m, False, one_chip)
+    compiled = pagerank_loop.lower(g, damping=0.85, tol=1e-6 / n,
+                                   max_iters=300).compile()
     ma = compiled.memory_analysis()
     assert ma.argument_size_in_bytes >= 2 * m * 4
+
+
+def test_edge_map_scopes_survive_the_chip_fusion_pass(one_chip):
+    """At the benchmark's ``kron21`` shapes (2^21 vertices, 2^26 arc slots)
+    every fusion over the arcs carries one of its app's scopes, and the
+    gather, the active-flag gather and the reduction each keep fusions of
+    their own, so a device trace reads them apart. The count of such
+    fusions per scope is pinned: the per-scope benchmark metrics read
+    these fusions, so a change that moves work between scopes changes
+    what those metrics measure and has to show here."""
+    import collections
+
+    from test_obs import arc_sized_fusions
+
+    from repro import obs
+    from repro.apps.pagerank import pagerank_loop
+    from repro.apps.sssp import sssp_loop
+
+    n, m = 1 << 21, 1 << 26
+    programs = {
+        "pagerank": (pagerank_loop.lower(
+            _graph(n, m, False, one_chip), 0.85,
+            _sds((), jnp.float32, one_chip), max_iters=20),
+            {obs.GATHER: 4, obs.REDUCE: 2, obs.OUT_DEGREE: 2}),
+        "sssp": (sssp_loop.lower(
+            _graph(n, m, True, one_chip), _sds((), jnp.int32, one_chip)),
+            {obs.GATHER: 1, obs.FRONTIER: 4, obs.REDUCE: 2}),
+    }
+    for app, (lowered, per_scope) in programs.items():
+        text = lowered.compile().as_text()
+        scope_of = obs.scopes_of_hlo(text)
+        fusions = arc_sized_fusions(text, m)
+        assert collections.Counter(scope_of[f] for f in fusions) == \
+            per_scope, app
 
 
 def test_mind_serve_step_compiles_at_published_widths(one_chip):
