@@ -94,8 +94,13 @@ def test_edge_map_scopes_survive_the_chip_fusion_pass(one_chip):
     their own, so a device trace reads them apart. The count of such
     fusions per scope is pinned: the per-scope benchmark metrics read
     these fusions, so a change that moves work between scopes changes
-    what those metrics measure and has to show here."""
+    what those metrics measure and has to show here.
+
+    PageRank's pull reduction is a segmented scan over the in-CSR's sorted
+    rows (``engine.reduce_rows``), with no scatter under
+    ``edge_map.reduce``; SSSP's push reduction keeps its scatter-min."""
     import collections
+    import re
 
     from test_obs import arc_sized_fusions
 
@@ -108,17 +113,23 @@ def test_edge_map_scopes_survive_the_chip_fusion_pass(one_chip):
         "pagerank": (pagerank_loop.lower(
             _graph(n, m, False, one_chip), 0.85,
             _sds((), jnp.float32, one_chip), max_iters=20),
-            {obs.GATHER: 4, obs.REDUCE: 2, obs.OUT_DEGREE: 2}),
+            {obs.GATHER: 4, obs.REDUCE: 13, obs.OUT_DEGREE: 2},
+            {obs.OUT_DEGREE: 1}),
         "sssp": (sssp_loop.lower(
             _graph(n, m, True, one_chip), _sds((), jnp.int32, one_chip)),
-            {obs.GATHER: 1, obs.FRONTIER: 4, obs.REDUCE: 2}),
+            {obs.GATHER: 1, obs.FRONTIER: 4, obs.REDUCE: 2},
+            {obs.REDUCE: 1}),
     }
-    for app, (lowered, per_scope) in programs.items():
+    for app, (lowered, per_scope, scatters) in programs.items():
         text = lowered.compile().as_text()
         scope_of = obs.scopes_of_hlo(text)
         fusions = arc_sized_fusions(text, m)
         assert collections.Counter(scope_of[f] for f in fusions) == \
             per_scope, app
+        found = re.findall(r"^\s+(?:ROOT\s+)?%?([\w.\-]+) = \S+ scatter\(",
+                           text, re.MULTILINE)
+        assert collections.Counter(scope_of[f] for f in found) == \
+            scatters, app
 
 
 def test_mind_serve_step_compiles_at_published_widths(one_chip):

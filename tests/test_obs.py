@@ -1,6 +1,7 @@
 """The apps' own counts and scopes (``repro.obs``): PageRank's iterations
 and SSSP's rounds and frontiers against float64 numpy loops, the public
 entries against the loops they call, and the scope map of a CPU compile."""
+import math
 import re
 import sys
 
@@ -170,20 +171,24 @@ def test_registry_is_bounded_and_keeps_no_traced_call():
 
 def arc_sized_fusions(text: str, m: int) -> list:
     """Fusion instructions whose result or fused parameters hold ``m``
-    elements."""
+    elements, in any shape (``[m]``, ``[m,1]``, ``[m/128,128]``)."""
     params, fusions = {}, []
-    arc = re.compile(rf"\[{m}(,1)?\]")
+
+    def holds_m(shapes):
+        return any(math.prod(int(d) for d in dims.split(",")) == m
+                   for dims in re.findall(r"\[(\d+(?:,\d+)*)\]", shapes))
+
     for line in text.splitlines():
         head = re.match(r"^%?([\w.\-]+) \((.*)\) -> ", line)
         if head:
             params[head.group(1)] = head.group(2)
             continue
-        f = re.match(r"^\s+(?:ROOT\s+)?%?([\w.\-]+) = (\S+) fusion\(.*"
+        f = re.match(r"^\s+(?:ROOT\s+)?%?([\w.\-]+) = (.+?) fusion\(.*"
                      r"calls=%?([\w.\-]+)", line)
         if f:
             fusions.append((f.group(1), f.group(2), f.group(3)))
     return [name for name, shape, called in fusions
-            if arc.search(shape) or arc.search(params.get(called, ""))]
+            if holds_m(shape) or holds_m(params.get(called, ""))]
 
 
 def test_scope_map_gives_every_arc_sized_fusion_a_scope():
