@@ -123,14 +123,23 @@ def test_benchmark_files_alone_give_no_result(tmp_path):
 
 
 def test_metrics_for_cells():
+    """Every cell reports ``setup_s`` and at least one more end-to-end
+    metric (those that name it, and those that name no cell), and with
+    ``--trace 1`` the per-layer metrics that name it, each moving one of
+    its end-to-end metrics; every per-layer metric names its cells and
+    every metric has a reader. The expected sets come from
+    ``BENCHMARK.json``, so a new cell or metric is covered as it lands."""
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())
-    cells = {c["name"]: c for c in bench["workloads"]}
     names = lambda ms: {m["name"] for m in ms}  # noqa: E731
-    for cell, job in (("kron21.pr", "pr"), ("kron21.sssp", "sssp")):
-        assert names(run.metrics_for(bench, cells[cell], False)) == {
-            f"{job}.job_s", "setup_s"}
-        assert names(run.metrics_for(bench, cells[cell], True)) == {
-            f"{job}.hbm_roofline", f"{job}.idle_share"} | (
-                {"sssp.round_ms"} if job == "sssp" else set())
+    assert all("workloads" in m for m in bench["per_layer"])
+    for cell in bench["workloads"]:
+        name = cell["name"]
+        e2e = {m["name"] for m in bench["end_to_end"]
+               if name in m.get("workloads", [name])}
+        assert "setup_s" in e2e and len(e2e) >= 2, name
+        assert names(run.metrics_for(bench, cell, False)) == e2e
+        layer = [m for m in bench["per_layer"] if name in m["workloads"]]
+        assert layer and all(m["moves"] in e2e for m in layer), name
+        assert names(run.metrics_for(bench, cell, True)) == names(layer)
     for m in bench["end_to_end"] + bench["per_layer"]:
         assert (ROOT / "chipbench" / "metrics" / f"{m['name']}.py").is_file()
